@@ -25,6 +25,7 @@ from benchmarks import (
     table2_summary,
     variants_bench,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 ALL = {
     "fig5": fig5_linearity.main,
@@ -62,6 +63,7 @@ def main() -> None:
             file=sys.stderr, flush=True,
         )
         sys.exit(2)
+    enable_compile_cache()
     quick = not args.full
     failed = []
     for name in names:

@@ -1,7 +1,8 @@
 #!/usr/bin/env bash
-# Tier-1 verify with base deps only: the suite must collect and pass
-# without the optional extras (zstandard, hypothesis) — optional-dep
-# imports are gated in-tree, and this is the command CI runs.
+# Tier-1 verify: the linter, then the test suite on the CPU (Pallas in
+# interpret mode). The installed stack has the extras (zstandard,
+# hypothesis); their imports stay gated in-tree so a base install still
+# collects. This is the command CI runs.
 #
 # Tests marked @pytest.mark.slow (long-grid calibration sweeps, full
 # benchmark-scale evals) are deselected by default via pyproject's

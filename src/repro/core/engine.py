@@ -74,11 +74,10 @@ class CIMPolicyLike(Protocol):
 # PlannedWeights
 # ---------------------------------------------------------------------------
 
-
 @functools.partial(
     jax.tree_util.register_dataclass,
     data_fields=("codes", "scale", "colsum", "w", "planes", "slots"),
-    meta_fields=("weight_bits",),
+    meta_fields=("weight_bits", "column_axis"),
 )
 @dataclasses.dataclass(frozen=True)
 class PlannedWeights:
@@ -112,6 +111,17 @@ class PlannedWeights:
                cannot be regrouped — a spec with a different
                ``rows_active`` simply doesn't use it.
       weight_bits: static weight precision (pytree metadata).
+      column_axis: static; None except in the per-device view of a
+               column-sharded plan (``distributed.sharding.per_device``),
+               where ``codes``, ``w`` and ``planes`` hold this device's
+               output columns while ``scale`` and ``colsum`` stay whole.
+               It names the mesh axis the columns are split over.
+
+    Every read of a plan yields whole columns, column shard or not: the
+    macro path all-gathers its integer result before the epilogue
+    (:meth:`whole_columns`), and :meth:`dequantized` /
+    :meth:`best_weights` gather the weights. So all float work runs on
+    whole rows, as on one device.
     """
 
     codes: Any
@@ -121,6 +131,7 @@ class PlannedWeights:
     planes: Any = None
     slots: Any = None
     weight_bits: int = 8
+    column_axis: str | None = None
 
     # -- convenience views -------------------------------------------------
 
@@ -130,21 +141,31 @@ class PlannedWeights:
 
     @property
     def n(self) -> int:
-        return self.codes.shape[-1]
+        """Output width; whole even when ``codes`` is a column shard."""
+        return self.scale.shape[-1]
 
     @property
     def codes_i32(self) -> jax.Array:
         c = self.codes
         return c if c.dtype == jnp.int32 else c.astype(jnp.int32)
 
+    def whole_columns(self, y: jax.Array) -> jax.Array:
+        """[..., n] from a result over this plan's own columns: the
+        identity, except in a column shard, which all-gathers them."""
+        if self.column_axis is None:
+            return y
+        return jax.lax.all_gather(y, self.column_axis, axis=y.ndim - 1,
+                                  tiled=True)
+
     def dequantized(self, dtype=jnp.float32) -> jax.Array:
         """w ~= scale * codes (the digital int8 serving read path)."""
-        return self.codes.astype(dtype) * self.scale.astype(dtype)
+        codes = self.whole_columns(self.codes)
+        return codes.astype(dtype) * self.scale.astype(dtype)
 
     def best_weights(self, dtype=jnp.float32) -> jax.Array:
         """Full-precision weights if kept, else the dequantized codes."""
         if self.w is not None:
-            return self.w.astype(dtype)
+            return self.whole_columns(self.w).astype(dtype)
         return self.dequantized(dtype)
 
 
@@ -426,12 +447,12 @@ def quantized_backend(int_fn) -> BackendFn:
             symmetric=policy.act_symmetric,
             clip_pct=policy.act_clip_pct,
         )
-        y_int = int_fn(qa.codes, plan, cfg, key)
+        y_int = plan.whole_columns(int_fn(qa.codes, plan, cfg, key))
         colsum = plan.colsum
         if colsum is None:  # minimal plans: recover digitally (free)
-            colsum = jnp.sum(
+            colsum = plan.whole_columns(jnp.sum(
                 plan.codes_i32, axis=-2, keepdims=True
-            ).astype(jnp.float32)
+            ).astype(jnp.float32))
         y = y_int - qa.zero_point.astype(jnp.float32) * colsum
         return y * qa.scale * plan.scale
 

@@ -267,28 +267,37 @@ def plan_shardings(plan: Any, mesh: Mesh) -> Any:
     """NamedShardings for one ``engine.PlannedWeights``.
 
     Every stored-weight tensor is tensor-parallel over the model axis
-    on its output-channel (N) dim — codes [..., K, N], kept fp weights,
-    the [..., 1, N] epilogue vectors, and the pre-grouped ``planes`` in
-    BOTH storage forms (unpacked [G, B, rows, N] int8 and bit-packed
-    [G, rows, N] uint8): the group/plane/row dims are the contraction
-    structure and must stay local to a shard, while N is embarrassingly
-    parallel — each model shard holds the planes of its own output
-    columns, so planned decode scales across devices without
-    re-planning (divisibility-aware: an indivisible N degrades to
-    replicated, like every rule here).
+    on its output-channel (N) dim — codes [..., K, N], kept fp weights
+    and the pre-grouped ``planes`` in BOTH storage forms (unpacked
+    [G, B, rows, N] int8 and bit-packed [G, rows, N] uint8): the
+    group/plane/row dims are the contraction structure and must stay
+    local to a shard, while N is embarrassingly parallel — each model
+    shard holds the planes of its own output columns, so planned decode
+    scales across devices without re-planning (divisibility-aware: an
+    indivisible N degrades to replicated, like every rule here).
+
+    The [..., 1, N] epilogue vectors (scale, colsum) stay whole: a
+    step under :func:`per_device` gathers the integer macro result and
+    runs the epilogue on whole rows (see ``engine.PlannedWeights``).
+    ``slots`` stay whole too: their slot-major last dim does not split
+    by output column, and the per-device view does not use them.
     """
     import dataclasses as _dc
 
     def one(v):
         return None if v is None else _last_dim_model(tuple(v.shape), mesh)
 
+    def whole(v):
+        return None if v is None else replicated(mesh)
+
     return _dc.replace(
         plan,
         codes=one(plan.codes),
-        scale=one(plan.scale),
-        colsum=one(plan.colsum),
+        scale=whole(plan.scale),
+        colsum=whole(plan.colsum),
         w=one(plan.w),
         planes=one(plan.planes),
+        slots=whole(plan.slots),
     )
 
 
@@ -325,6 +334,46 @@ def shard_planned(planned_tree: Any, mesh: Mesh | None) -> Any:
     )
 
 
+def per_device(fn, planned_tree: Any, mesh: Mesh):
+    """Run ``fn(params, *args)`` once on every device of ``mesh``.
+
+    ``params`` must be laid out as :func:`shard_planned` places
+    ``planned_tree``; every other argument and every output is whole on
+    each device. Inside, a plan whose output columns are split arrives
+    as a column shard (``PlannedWeights.column_axis`` set, its whole
+    ``slots`` dropped), so each of its reads gathers whole columns and
+    ``fn`` computes what it computes on one device, plus the gathers:
+    the weight matmuls split across devices, all other work (epilogue,
+    norms, attention, caches) is repeated on each. Mesh axes other than
+    'model' split nothing.
+    """
+    import dataclasses as _dc
+
+    from repro.core.engine import PlannedWeights  # lazy: keep import light
+
+    shardings = planned_param_shardings(planned_tree, mesh)
+
+    def is_plan(x):
+        return isinstance(x, PlannedWeights)
+
+    def view(node, sh):
+        if not is_plan(node) or sh.codes.spec[-1] is None:
+            return node
+        return _dc.replace(node, column_axis=sh.codes.spec[-1], slots=None)
+
+    def body(params, args):
+        params = jax.tree.map(view, params, shardings, is_leaf=is_plan)
+        return fn(params, *args)
+
+    mapped = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(jax.tree.map(lambda s: s.spec, shardings),
+                  PartitionSpec()),
+        out_specs=PartitionSpec(), check_vma=False,
+    )
+    return lambda params, *args: mapped(params, args)
+
+
 def opt_state_axes(param_axes: Any, opt_state) -> Any:
     """AdamW m/v inherit the param axes; step/rng are replicated."""
     from repro.optim.adamw import AdamWState
@@ -359,10 +408,12 @@ _ACT_RULES: dict[str, tuple[str, ...]] = {
 def constrain(x, axes: tuple[str | None, ...]):
     """with_sharding_constraint by logical activation axes.
 
-    No-op when no mesh context is active (probe/smoke paths) or when a
-    dim is not divisible by its mesh axes. Model code calls this at the
-    few propagation cliffs (logits, embed output, FFN hidden) -- the
-    MaxText pattern.
+    No-op when no mesh context (``jax.set_mesh``) is active
+    (probe/smoke paths); a dim not divisible by its mesh axes stays
+    unconstrained. Errors from the constraint itself propagate: a
+    broken layout must not silently run unsharded. Model code calls
+    this at the few propagation cliffs (logits, embed output, FFN
+    hidden) -- the MaxText pattern.
     """
     mesh = _ctx_mesh()
     if mesh is None:
@@ -385,28 +436,17 @@ def constrain(x, axes: tuple[str | None, ...]):
             entries.append(names[0])
         else:
             entries.append(tuple(names))
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, PartitionSpec(*entries)
-        )
-    except (ValueError, RuntimeError):
-        return x
+    return jax.lax.with_sharding_constraint(x, PartitionSpec(*entries))
 
 
 def _ctx_mesh():
-    # jax < 0.5 has no get_abstract_mesh; only the legacy `with mesh:`
-    # thread-resource context below exists there.
-    get_abstract_mesh = getattr(jax.sharding, "get_abstract_mesh", None)
-    mesh = get_abstract_mesh() if get_abstract_mesh is not None else None
-    if mesh is not None and not mesh.empty and mesh.shape:
-        return mesh
-    try:  # legacy `with mesh:` context
-        from jax._src import mesh as _mesh_lib  # noqa: PLC0415
+    """The ``jax.set_mesh`` context mesh, or None outside one.
 
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-    except Exception:  # noqa: BLE001
-        return None
-    if mesh is None or mesh.empty or not mesh.shape:
+    None inside a ``shard_map`` as well: its specs fix the body's
+    layout, and its axes are manual, which a constraint may not name.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or not mesh.shape or mesh.manual_axes:
         return None
     return mesh
 
@@ -442,7 +482,4 @@ def constrain_query(q):
             spec[2] = "model"
         elif s % model == 0:
             spec[1] = "model"
-    try:
-        return jax.lax.with_sharding_constraint(q, PartitionSpec(*spec))
-    except (ValueError, RuntimeError):
-        return q
+    return jax.lax.with_sharding_constraint(q, PartitionSpec(*spec))

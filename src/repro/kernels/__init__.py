@@ -4,7 +4,8 @@ cim_mac.py  : GPQ (grouped-partial-sum quantized) matmuls — the macro's
               16-row ABL accumulation + fused variant transfers (P-8T
               flash, adder-tree merged single-ADC, cell-embedded SAR),
               VMEM-tiled.
-ops.py      : jit'd wrappers (TPU native / interpret-mode on CPU).
+ops.py      : jit'd wrappers; the one place that decides native
+              Mosaic lowering (TPU) vs Pallas interpret mode.
 ref.py      : pure-jnp vectorized oracles, doubling as the dispatch
               table's "ref" backend.
 dispatch.py : the KernelKey(variant, backend, shape_cell, dtype) ->
@@ -26,9 +27,14 @@ from repro.kernels.ops import (
     cell_adc_matmul_kernel,
     cim_matmul_kernel,
 )
-from repro.kernels.ref import adder_tree_matmul_ref, cim_matmul_ref
+from repro.kernels.ref import (
+    KernelInfeasible,
+    adder_tree_matmul_ref,
+    cim_matmul_ref,
+)
 
 __all__ = [
+    "KernelInfeasible",
     "KernelKey",
     "adder_tree_gpq_matmul",
     "adder_tree_matmul_kernel",

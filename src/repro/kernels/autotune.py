@@ -40,8 +40,10 @@ autotune renderer).
 
 Timing is injectable (``measure=``) so tests pin winners with a
 deterministic proxy; the default measures best-of-``reps`` wall time
-of a jitted call. Candidates that fail to trace/execute at the shape
-(e.g. a depth-guarded Pallas kernel) are skipped, never winners.
+of a jitted call. Candidates that raise ``KernelInfeasible`` at the
+shape (e.g. a depth-guarded Pallas kernel) are skipped and logged,
+never winners; any other error (a kernel the compiler refuses)
+propagates.
 """
 
 from __future__ import annotations
@@ -85,24 +87,26 @@ def decode_blocks(
 
     The default 128-row M tiles pad an m=1 decode step to 128 rows and
     burn 128x the FLOPs; these candidates pair small bm values
-    (``DECODE_BMS``, dropped above the next power of two of ``m`` so
-    an m=1 sweep times only bm=1) with bk values aligned to the
-    calibration's ``rows_active`` group (the kernel requires
-    rows | bk, and a rows-aligned bk avoids the dispatch adapter's
-    round-down losing contraction depth for non-power-of-two rows).
+    (``DECODE_BMS``) with bk values aligned to the calibration's
+    ``rows_active`` group (the kernel requires rows | bk, and a
+    rows-aligned bk avoids the dispatch adapter's round-down losing
+    contraction depth for non-power-of-two rows).
+
+    A TPU block's second-minor dim must be a multiple of 8 or the whole
+    (padded) array dim, so bm=1 is legal only for an m=1 call: an m=1
+    sweep times bm=1 alone, and any other (or unknown) m times the bm
+    values that are multiples of 8, up to the next power of two of
+    max(m, 8).
     """
-    cap = None
-    if m is not None:
-        cap = 1
-        while cap < m and cap < max(DECODE_BMS):
-            cap *= 2
+    if m == 1:
+        bms = (1,)
+    else:
+        bms = tuple(
+            bm for bm in DECODE_BMS
+            if bm % 8 == 0 and (m is None or bm < 2 * max(m, 8))
+        )
     bks = sorted({max(rows, 128 - 128 % rows), 8 * rows})
-    return tuple(
-        (bm, bn, bk)
-        for bm in DECODE_BMS
-        if cap is None or bm <= cap
-        for bk in bks
-    )
+    return tuple((bm, bn, bk) for bm in bms for bk in bks)
 
 Candidate = tuple[str, tuple[int, int, int] | None]
 # measure(candidate, run) -> seconds for one call; `run` executes the
@@ -467,7 +471,11 @@ def sweep_shape(
         )
         try:
             jax.block_until_ready(fn(x, w, planes, slots))
-        except Exception:  # noqa: BLE001 - infeasible candidate (depth guard...)
+        except dispatch.KernelInfeasible as e:
+            logger.info(
+                "autotune %s (%d, %d, %d): skipping %s block=%s: %s",
+                variant, m, k, n, backend, block, e,
+            )
             continue
         secs = float(measure(
             (backend, block),

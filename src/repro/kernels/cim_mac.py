@@ -8,11 +8,11 @@ macro variant to its kernel; the notes below describe the shared
 structure through the P-8T instance.
 
 This is the perf-critical hot spot of the paper's technique mapped to
-TPU (DESIGN.md Sec. 2): the 16-row ABL charge-sharing accumulation
-becomes a grouped contraction, and the ADC transfer (cutoff clip + floor
-quantization + bit-plane shift-add) is fused onto the partial-sum tile
-while it lives in VMEM -- one HBM round trip per output tile instead of
-one per (group x bit-plane) intermediate, which is what the naive jnp
+TPU: the 16-row ABL charge-sharing accumulation becomes a grouped
+contraction, and the ADC transfer (cutoff clip + floor quantization +
+bit-plane shift-add) is fused onto the partial-sum tile while it lives
+in VMEM -- one HBM round trip per output tile instead of one per
+(group x bit-plane) intermediate, which is what the naive jnp
 formulation pays.
 
 Tiling (BlockSpec):
@@ -22,29 +22,32 @@ Tiling (BlockSpec):
                       (i32 from quantize_acts; widened to f32 inside
                       the tile — the HBM->VMEM stream stays narrow)
   w tile   [bk, bn]   weight codes: i8/i32 signed plan codes OR a
-                      plan's packed-plane bytes (u8) — the in-tile
-                      two's-complement unpack masks to the low
-                      ``weight_bits`` either way, so both storage
-                      forms lower through one kernel
+                      plan's packed-plane bytes (u8) — plane b is bit b
+                      of the widened value either way (sign extension
+                      and zero extension agree on the low weight_bits),
+                      so both storage forms lower through one kernel
   out tile [bm, bn]   f32 accumulated shift-add results
 
-Inside one k step the kernel unpacks the two's-complement planes of the
-w tile (b planes -> the expanded [gk, rows, B*bn] operand), runs one
-batched MXU contraction per group batch
-  [gk, bm, rows] x [gk, rows, B*bn] -> [gk, bm, B*bn]
-and applies the ADC nonlinearity elementwise before reducing (g, b) into
-the output tile.
+Inside one k step the kernel extracts the B two's-complement planes of
+the w tile as [bk, bn] 0/1 tiles and runs, per 16-row group g and plane
+b, one MXU contraction
+  x[:, g*rows:(g+1)*rows] @ plane_b[g*rows:(g+1)*rows]  -> [bm, bn]
+followed by the ADC nonlinearity and the shift-add into the output
+tile. Groups and planes are static Python loops: Mosaic lowers static
+slices and 2-D dots, whereas the batched form ([gk, bm, rows] x
+[gk, rows, B*bn]) needs a lane-dim reshape it refuses, and a traced
+per-plane sign vector would be a captured array constant. Plane weights
+are Python floats folded into the code.
 
 The MXU sees a contraction depth of rows (16): that granularity is
 *semantic* -- the ADC sits between 16-row groups, so deeper contraction
 would change the computed function. This bounds achievable MXU
-utilization at rows/128 for the faithful mode; see EXPERIMENTS.md
-Sec. Perf for the measured consequences and the cim-exact escape hatch.
+utilization at rows/128 for the faithful mode.
 
 f32 accumulation is exact for integers < 2**24; with |contrib| per
-(group, plane) <= 2**(B-1) * threshold the wrapper asserts
+(group, plane) <= 2**(B-1) * threshold the wrapper requires
 K / rows * 2**(B-1) * threshold < 2**24 (K <~ 16k at the paper op point)
-and falls back to the jnp path beyond that.
+and raises ``KernelInfeasible`` beyond that.
 """
 
 from __future__ import annotations
@@ -54,57 +57,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.params import CIMConfig
 from repro.core.pipeline import MacroSpec
+from repro.kernels.ref import KernelInfeasible, _plane_sign, pmac_precision
 
 
-def _grouped_plane_pmac(x, w, rows: int, weight_bits: int):
-    """Shared kernel prologue: tile codes -> grouped plane partial-MACs.
+def _tile_planes(x_ref, w_ref, weight_bits: int):
+    """Widen one (x, w) tile pair: f32 codes plus B [bk, bn] 0/1 planes.
 
-    x [bm, bk] activation codes (any integer or f32 dtype), w [bk, bn]
-    weight codes in any storage form — signed i8/i32 plan codes or a
-    plan's packed-plane u8 bytes (whose low ``weight_bits`` ARE the
-    masked two's-complement code bits) -> pmac [gk, bm, B*bn] f32
-    (exact integers) plus (bm, bn, gk, b). Widening to f32/i32 happens
-    here, on the VMEM-resident tile, not on the HBM operands.
+    Widening to f32/i32 happens here, on the VMEM-resident tile, not on
+    the HBM operands. Bit b of the i32-widened weight is the weight's
+    two's-complement bit b for i8/i32 codes (sign extension) and for
+    packed u8 plane bytes (zero extension) alike.
+    """
+    x = x_ref[...].astype(jnp.float32)
+    u = w_ref[...].astype(jnp.int32)
+    planes = [
+        jnp.bitwise_and(jnp.right_shift(u, b), 1).astype(jnp.float32)
+        for b in range(weight_bits)
+    ]
+    return x, planes
+
+
+def _group_pmacs(x, planes, g: int, rows: int, precision):
+    """Plane partial MACs of row group ``g``: B tiles of [bm, bn] f32.
+
+    Static lane/sublane slices of the group's rows and one 2-D MXU
+    contraction per plane (at ``ref.pmac_precision``); the results are
+    exact integers.
     """
     # One 0/1-plane group contraction is a pMAC: exact in f32 as long
     # as the worst group partial sum clears the mantissa with room.
     # bound(CIM601): pmac_max < 2**24
-    bm, bk = x.shape
-    bn = w.shape[1]
-    gk = bk // rows
-    b = weight_bits
-    x = x.astype(jnp.float32)
-
-    # Two's-complement plane expansion: [bk, bn] -> [bk, B, bn] 0/1.
-    # i8 codes sign-extend then mask to their low b bits; u8 packed
-    # bytes mask identically — one unpack serves both storage forms.
-    mask = (1 << b) - 1
-    u = jnp.bitwise_and(w.astype(jnp.int32), mask)
-    shifts = jnp.arange(b, dtype=jnp.int32)[None, :, None]
-    planes = jnp.bitwise_and(
-        jnp.right_shift(u[:, None, :], shifts), 1
-    ).astype(jnp.float32)
-    # Group the contraction dim: [gk, rows, B*bn].
-    pe = planes.reshape(gk, rows, b * bn)
-
-    # Group the activations: [gk, bm, rows].
-    xg = x.reshape(bm, gk, rows).transpose(1, 0, 2)
-
-    # Batched MXU contraction over the 16-row groups.
-    pmac = jax.lax.dot_general(
-        xg,
-        pe,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32,
-    )  # [gk, bm, B*bn]
-    return pmac, (bm, bn, gk, b)
-
-
-def _plane_signs_f32(b: int):
-    return (2.0 ** jnp.arange(b, dtype=jnp.float32)).at[b - 1].multiply(-1.0)
+    lo, hi = g * rows, (g + 1) * rows
+    xg = x[:, lo:hi]
+    return [
+        jnp.dot(
+            xg, p[lo:hi],
+            precision=precision,
+            preferred_element_type=jnp.float32,
+        )
+        for p in planes
+    ]
 
 
 def _gpq_kernel(
@@ -114,6 +110,7 @@ def _gpq_kernel(
     *,
     rows: int,
     weight_bits: int,
+    precision: jax.lax.Precision,
     adc_step: float,
     adc_codes: int,
     nearest: bool = False,
@@ -125,19 +122,17 @@ def _gpq_kernel(
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    pmac, (bm, bn, gk, b) = _grouped_plane_pmac(
-        x_ref[...], w_ref[...], rows, weight_bits
-    )
-
+    x, planes = _tile_planes(x_ref, w_ref, weight_bits)
     # Fused ADC transfer: cutoff clip + floor (or round-to-nearest)
     # quantization, then the digital shift-add with the MSB plane
     # negative (two's complement).
     half = 0.5 if nearest else 0.0
-    code = jnp.clip(jnp.floor(pmac / adc_step + half), 0, adc_codes - 1)
-    deq = code.reshape(gk, bm, b, bn) * adc_step
-    contrib = jnp.einsum("gmbn,b->mn", deq, _plane_signs_f32(b))
-
-    out_ref[...] += contrib
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for g in range(x.shape[1] // rows):
+        for b, pmac in enumerate(_group_pmacs(x, planes, g, rows, precision)):
+            code = jnp.clip(jnp.floor(pmac / adc_step + half), 0, adc_codes - 1)
+            acc = acc + code * (_plane_sign(b, weight_bits) * adc_step)
+    out_ref[...] += acc
 
 
 def _adder_tree_kernel(
@@ -147,6 +142,7 @@ def _adder_tree_kernel(
     *,
     rows: int,
     weight_bits: int,
+    precision: jax.lax.Precision,
     step: float,
     code_min: int,
     code_max: int,
@@ -165,21 +161,22 @@ def _adder_tree_kernel(
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    pmac, (bm, bn, gk, b) = _grouped_plane_pmac(
-        x_ref[...], w_ref[...], rows, weight_bits
-    )
-    # Charge-domain merge: [gk, bm, b, bn] x signs -> [gk, bm, bn].
-    merged = jnp.einsum(
-        "gmbn,b->gmn", pmac.reshape(gk, bm, b, bn), _plane_signs_f32(b)
-    )
+    x, planes = _tile_planes(x_ref, w_ref, weight_bits)
     half = 0.5 if nearest else 0.0
-    code = jnp.clip(
-        jnp.floor(merged / step + half), code_min, code_max
-    )
+    codes = jnp.zeros(out_ref.shape, jnp.float32)
+    for g in range(x.shape[1] // rows):
+        # Charge-domain merge of the group's plane pMACs.
+        merged = None
+        for b, pmac in enumerate(_group_pmacs(x, planes, g, rows, precision)):
+            term = pmac * _plane_sign(b, weight_bits)
+            merged = term if merged is None else merged + term
+        codes = codes + jnp.clip(
+            jnp.floor(merged / step + half), code_min, code_max
+        )
     # Zero-padded groups merge to 0 -> code 0 -> no contribution, so K
     # padding stays benign. Codes are exact integers; the common factor
     # `step` is applied after the group reduction.
-    out_ref[...] += jnp.sum(code, axis=0) * step
+    out_ref[...] += codes * step
 
 
 def _cell_adc_kernel(
@@ -189,6 +186,7 @@ def _cell_adc_kernel(
     *,
     rows: int,
     weight_bits: int,
+    precision: jax.lax.Precision,
     adc_step: float,
     adc_bits: int,
     nearest: bool,
@@ -208,19 +206,22 @@ def _cell_adc_kernel(
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    pmac, (bm, bn, gk, b) = _grouped_plane_pmac(
-        x_ref[...], w_ref[...], rows, weight_bits
-    )
+    x, planes = _tile_planes(x_ref, w_ref, weight_bits)
     # 'nearest' shifts every decision threshold by half an LSB; 'floor'
     # compares against the reference levels directly.
     thresh_off = 0.5 * adc_step if nearest else 0.0
-    code = jnp.zeros(pmac.shape, dtype=jnp.int32)
-    for bit in range(adc_bits - 1, -1, -1):  # static unrolled SAR loop
-        trial = jnp.bitwise_or(code, 1 << bit)
-        take = pmac + thresh_off >= trial.astype(jnp.float32) * adc_step
-        code = jnp.where(take, trial, code)
-    deq = code.astype(jnp.float32).reshape(gk, bm, b, bn) * adc_step
-    out_ref[...] += jnp.einsum("gmbn,b->mn", deq, _plane_signs_f32(b))
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for g in range(x.shape[1] // rows):
+        for b, pmac in enumerate(_group_pmacs(x, planes, g, rows, precision)):
+            code = jnp.zeros(pmac.shape, dtype=jnp.int32)
+            for bit in range(adc_bits - 1, -1, -1):  # static SAR loop
+                trial = jnp.bitwise_or(code, 1 << bit)
+                take = pmac + thresh_off >= trial.astype(jnp.float32) * adc_step
+                code = jnp.where(take, trial, code)
+            acc = acc + code.astype(jnp.float32) * (
+                _plane_sign(b, weight_bits) * adc_step
+            )
+    out_ref[...] += acc
 
 
 def _tiled_call(kernel, x_codes, w_codes, *, bm, bn, bk, interpret):
@@ -241,22 +242,9 @@ def _tiled_call(kernel, x_codes, w_codes, *, bm, bn, bk, interpret):
     x_p = jnp.pad(x_codes, ((0, mp - m), (0, kp - k)))
     w_p = jnp.pad(w_codes, ((0, kp - k), (0, np_ - n)))
 
-    grid = (mp // bm, np_ // bn, kp // bk)
-    kwargs = {}
-    if not interpret:
-        # TPU compiler hints: m/n parallel, k sequential (accumulation).
-        from jax.experimental.pallas import tpu as pltpu  # noqa: PLC0415
-
-        params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-            pltpu, "TPUCompilerParams"
-        )
-        kwargs["compiler_params"] = params_cls(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
-        )
-
     out = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(mp // bm, np_ // bn, kp // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
@@ -264,7 +252,10 @@ def _tiled_call(kernel, x_codes, w_codes, *, bm, bn, bk, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
-        **kwargs,
+        # TPU compiler hints: m/n parallel, k sequential (accumulation).
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+        ),
     )(x_p, w_p)
     return out[:m, :n]
 
@@ -282,10 +273,10 @@ def gpq_matmul(
     w_codes: jax.Array,
     cfg: CIMConfig | MacroSpec,
     *,
+    interpret: bool,
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     """Pallas GPQ matmul. x: [M, K] codes, w: [K, N] signed codes.
 
@@ -294,7 +285,9 @@ def gpq_matmul(
     group geometry (``rows_active``) and the ADC transfer constants
     (``adc_step``/``adc_codes``/``threshold``) from the stage specs
     rather than raw config fields, so swept/calibrated specs lower
-    without a config round-trip.
+    without a config round-trip. ``interpret`` has no default: native
+    Mosaic lowering or the Pallas interpreter is the caller's choice
+    (``kernels.ops`` decides it from the backend).
     """
     cfg = MacroSpec.from_config(cfg)
     m, k = x_codes.shape
@@ -307,7 +300,7 @@ def gpq_matmul(
     # bound(CIM601): G * 2**(weight_bits - 1) * threshold < 2**23 * adc_step
     max_abs = (k + rows - 1) // rows * (1 << (cfg.weight_bits - 1)) * cfg.threshold
     if max_abs >= (1 << 24) * 0.5 * cfg.adc_step:
-        raise ValueError(
+        raise KernelInfeasible(
             f"K={k} too deep for exact f32 accumulation at this operating "
             "point; use core.matmul.cim_matmul_int"
         )
@@ -316,6 +309,7 @@ def gpq_matmul(
         _gpq_kernel,
         rows=rows,
         weight_bits=cfg.weight_bits,
+        precision=pmac_precision(cfg.act_bits),
         adc_step=float(cfg.adc_step),
         adc_codes=cfg.adc_codes,
         nearest=cfg.adc_mode == "nearest",
@@ -333,10 +327,10 @@ def adder_tree_gpq_matmul(
     w_codes: jax.Array,
     cfg: CIMConfig | MacroSpec,
     *,
+    interpret: bool,
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     """Pallas kernel for the adder-tree merged transfer (arXiv:2212.04320).
 
@@ -361,7 +355,7 @@ def adder_tree_gpq_matmul(
     # bound(CIM601): G * max(-code_min, code_max) < 2**24
     g = (k + rows - 1) // rows
     if g * max(abs(mq.code_min), mq.code_max) >= (1 << 24):
-        raise ValueError(
+        raise KernelInfeasible(
             f"K={k} too deep for exact f32 accumulation of merged codes; "
             "use variants.adder_tree_matmul_int"
         )
@@ -370,6 +364,7 @@ def adder_tree_gpq_matmul(
         _adder_tree_kernel,
         rows=rows,
         weight_bits=cfg.weight_bits,
+        precision=pmac_precision(cfg.act_bits),
         step=float(mq.step),
         code_min=mq.code_min,
         code_max=mq.code_max,
@@ -388,10 +383,10 @@ def cell_adc_gpq_matmul(
     w_codes: jax.Array,
     cfg: CIMConfig | MacroSpec,
     *,
+    interpret: bool,
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     """Pallas kernel for the cell-embedded ADC readout (arXiv:2307.05944).
 
@@ -412,7 +407,7 @@ def cell_adc_gpq_matmul(
     # bound(CIM601): G * 2**(weight_bits - 1) * threshold < 2**23 * adc_step
     max_abs = (k + rows - 1) // rows * (1 << (cfg.weight_bits - 1)) * cfg.threshold
     if max_abs >= (1 << 24) * 0.5 * cfg.adc_step:
-        raise ValueError(
+        raise KernelInfeasible(
             f"K={k} too deep for exact f32 accumulation at this operating "
             "point; use core.matmul.cim_matmul_int"
         )
@@ -421,6 +416,7 @@ def cell_adc_gpq_matmul(
         _cell_adc_kernel,
         rows=rows,
         weight_bits=cfg.weight_bits,
+        precision=pmac_precision(cfg.act_bits),
         adc_step=float(cfg.adc_step),
         adc_bits=cfg.adc_bits,
         nearest=cfg.adc_mode == "nearest",

@@ -44,7 +44,11 @@ Resolution order when no backend is requested explicitly:
 
 An explicit ``backend=`` request is always honored (no silent
 fallback — ``record_resolutions`` lets callers and the check.sh guard
-assert exactly which implementation ran); an unknown key raises.
+assert exactly which implementation ran); an unknown key raises. An
+implicitly chosen implementation that raises ``KernelInfeasible`` (its
+own depth or operand guard) falls back to the scan, recorded as
+source="guard-fallback"; every other error propagates, so a kernel the
+device compiler refuses is never hidden behind the scan.
 
 An implementation is ``fn(x_codes, w_codes, spec, *, key=None,
 planes=None, block=None) -> [M, N] float32`` in integer-domain macro
@@ -69,6 +73,7 @@ from repro.core import variants as variants_lib
 from repro.core.params import CIMConfig
 from repro.core.pipeline import MacroSpec, as_spec
 from repro.kernels import ref as ref_lib
+from repro.kernels.ref import KernelInfeasible
 
 # fn(x_codes, w_codes, spec, *, key, planes, block) -> [M, N] f32
 KernelFn = Callable[..., jax.Array]
@@ -382,7 +387,7 @@ def dispatch(
         return run(impl, block)
     try:
         return run(impl, block)
-    except ValueError:
+    except KernelInfeasible:
         # Implicitly-chosen impl infeasible at this shape/operating
         # point (e.g. the Pallas f32 depth guard, a stale tuned pin):
         # fall back to the always-feasible scan transfer and RECORD it
@@ -438,7 +443,7 @@ def _slots_impl(attr: str) -> KernelFn:
             block=None):
         del w_codes, key, planes, block  # weight side IS the slot operand
         if slots is None:
-            raise ValueError(
+            raise KernelInfeasible(
                 "slots backend requires a plan's spread-slot operand "
                 "grouped at the executing rows_active "
                 "(engine.plan_weights(with_slots=True)); none provided"

@@ -8,6 +8,7 @@ kernels lower natively on TPU; everywhere else they run Pallas
 interpret mode (bit-exact semantics, executed on CPU), which is how
 the correctness sweeps in tests/test_kernels.py and
 tests/test_dispatch.py validate them against the integer oracles.
+This module is the one place that makes that choice (``_lower``).
 
 One wrapper per variant transfer:
 
@@ -35,8 +36,16 @@ from repro.kernels.cim_mac import (
 )
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _lower(kernel, x_codes, w_codes, cfg, bm, bn, bk) -> jax.Array:
+    """Run one GPQ kernel: native on TPU, the Pallas interpreter elsewhere.
+
+    XLA cannot partition a Mosaic kernel, so on several devices it must
+    run per device, inside a ``shard_map`` (``sharding.per_device``).
+    """
+    return kernel(
+        x_codes, w_codes, cfg, bm=bm, bn=bn, bk=bk,
+        interpret=jax.default_backend() != "tpu",
+    ).astype(jnp.float32)
 
 
 def cim_matmul_kernel(
@@ -55,15 +64,7 @@ def cim_matmul_kernel(
     its stage fields. Noiseless by design (production inference path);
     Monte-Carlo noise analysis uses the jnp behavioral model.
     """
-    return gpq_matmul(
-        x_codes,
-        w_codes,
-        cfg,
-        bm=bm,
-        bn=bn,
-        bk=bk,
-        interpret=_use_interpret(),
-    ).astype(jnp.float32)
+    return _lower(gpq_matmul, x_codes, w_codes, cfg, bm, bn, bk)
 
 
 def adder_tree_matmul_kernel(
@@ -79,15 +80,7 @@ def adder_tree_matmul_kernel(
 
     Drop-in for ``variants.adder_tree_matmul_int`` (noise off).
     """
-    return adder_tree_gpq_matmul(
-        x_codes,
-        w_codes,
-        cfg,
-        bm=bm,
-        bn=bn,
-        bk=bk,
-        interpret=_use_interpret(),
-    ).astype(jnp.float32)
+    return _lower(adder_tree_gpq_matmul, x_codes, w_codes, cfg, bm, bn, bk)
 
 
 def cell_adc_matmul_kernel(
@@ -104,15 +97,7 @@ def cell_adc_matmul_kernel(
     Bit-identical to the floor transfer noise-free — drop-in for
     ``matmul.cim_matmul_int`` at a cell-adc operating point.
     """
-    return cell_adc_gpq_matmul(
-        x_codes,
-        w_codes,
-        cfg,
-        bm=bm,
-        bn=bn,
-        bk=bk,
-        interpret=_use_interpret(),
-    ).astype(jnp.float32)
+    return _lower(cell_adc_gpq_matmul, x_codes, w_codes, cfg, bm, bn, bk)
 
 
 def register_tuned_backend(

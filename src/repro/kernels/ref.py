@@ -29,6 +29,34 @@ import jax.numpy as jnp
 from repro.core.params import CIMConfig
 from repro.core.quant import bitslice_weights, plane_signs, slot_spec
 
+# Every f32 contraction here carries exact integers (or integer codes
+# times the ADC step). TPU's DEFAULT precision rounds f32 operands to
+# bf16 (8 significant bits); slot words (up to ~2**16), signed plane
+# sums and codes times a general ADC step do not survive that, so those
+# contractions take HIGHEST.
+_EXACT = jax.lax.Precision.HIGHEST
+
+
+def pmac_precision(act_bits: int) -> jax.lax.Precision:
+    """Matmul precision of a pMAC: activation codes against 0/1 planes.
+
+    Codes below 2**8 are exact in bf16, so the single-pass DEFAULT
+    loses nothing there (accumulation is f32 either way); wider codes
+    take HIGHEST.
+    """
+    return jax.lax.Precision.DEFAULT if act_bits <= 8 else _EXACT
+
+
+class KernelInfeasible(ValueError):
+    """A kernel cannot run this shape / operating point / operand form.
+
+    Raised by the implementations' own feasibility guards (the f32
+    exact-accumulation depth limits, spread-slot geometry and operand
+    checks). ``dispatch`` falls back to the scan transfer on this error
+    alone, when the implementation was chosen implicitly; any other
+    exception — a kernel the compiler refuses, say — propagates.
+    """
+
 
 def _grouped_operands(x_codes, w_codes, cfg, planes):
     """Normalize (w_codes | plan planes) -> xg [M,G,rows], wp [B,G,rows,N]."""
@@ -64,13 +92,16 @@ def cim_matmul_ref(
     instead of re-slicing ``w_codes``.
     """
     xg, wp = _grouped_operands(x_codes, w_codes, cfg, planes)
-    pmac = jnp.einsum("mgr,bgrn->mgbn", xg, wp)
+    pmac = jnp.einsum("mgr,bgrn->mgbn", xg, wp,
+                      precision=pmac_precision(cfg.act_bits))
     half = 0.5 if getattr(cfg, "adc_mode", "floor") == "nearest" else 0.0
     code = jnp.clip(
         jnp.floor(pmac / cfg.adc_step + half), 0, cfg.adc_codes - 1
     )
     signs = plane_signs(cfg.weight_bits).astype(jnp.float32)
-    return jnp.einsum("mgbn,b->mn", code * cfg.adc_step, signs)
+    return jnp.einsum(
+        "mgbn,b->mn", code * cfg.adc_step, signs, precision=_EXACT
+    )
 
 
 def adder_tree_matmul_ref(
@@ -92,8 +123,9 @@ def adder_tree_matmul_ref(
     spec = cfg
     xg, wp = _grouped_operands(x_codes, w_codes, cfg, planes)
     signs = plane_signs(cfg.weight_bits).astype(jnp.float32)
-    pmac = jnp.einsum("mgr,bgrn->mgbn", xg, wp)
-    merged = jnp.einsum("mgbn,b->mgn", pmac, signs)
+    pmac = jnp.einsum("mgr,bgrn->mgbn", xg, wp,
+                      precision=pmac_precision(cfg.act_bits))
+    merged = jnp.einsum("mgbn,b->mgn", pmac, signs, precision=_EXACT)
     mq = merged_quant(spec)
     half = 0.5 if getattr(spec, "adc_mode", "floor") == "nearest" else 0.0
     code = jnp.clip(
@@ -127,18 +159,19 @@ def _slot_dot(x_codes, slots, spec):
     m, k = x_codes.shape
     g, rows, sn = slots.shape
     if rows != spec.rows_active:
-        raise ValueError(
+        raise KernelInfeasible(
             f"slots grouped at {rows} rows but spec.rows_active="
             f"{spec.rows_active}; re-plan (slots cannot be regrouped)"
         )
     if g * rows < k:
-        raise ValueError(
+        raise KernelInfeasible(
             f"slots cover K={g * rows} < input K={k}"
         )
     x = jnp.pad(x_codes.astype(jnp.float32), ((0, 0), (0, g * rows - k)))
     xg = x.reshape(m, g, rows).transpose(1, 0, 2)  # [G, M, rows]
     return jax.lax.dot_general(
         xg, slots, (((2,), (1,)), ((0,), (0,))),
+        precision=_EXACT,
         preferred_element_type=jnp.float32,
     )
 
@@ -171,13 +204,13 @@ def _plane_sign(b: int, weight_bits: int) -> float:
 def _slot_geometry(slots, spec):
     ss = slot_spec(spec.rows_active, spec.act_bits, spec.weight_bits)
     if ss is None:
-        raise ValueError(
+        raise KernelInfeasible(
             "spread slots infeasible at this operating point "
             f"(rows_active={spec.rows_active}, act_bits={spec.act_bits})"
         )
     sn = slots.shape[-1]
     if sn % ss.n_slots != 0:
-        raise ValueError(
+        raise KernelInfeasible(
             f"slots last dim {sn} is not divisible by n_slots="
             f"{ss.n_slots}; operand packed for a different operating "
             "point"

@@ -17,11 +17,6 @@ Per cell this records:
     the model; per-unit flops = f(2u) - f(1u), total = f(1u) +
     (n_units_effective - 1) * per_unit. Sequential time-recurrences
     (WKV) get documented analytic corrections.
-
-Runs on jax 0.4.37 as well as >=0.5: the ``jax.sharding.AxisType``
-mesh annotation this module reaches through ``launch.mesh`` is
-compat-gated there (dropped on old jax, where axes are implicitly
-Auto).
 """
 
 # The first two lines MUST run before any jax import: jax locks the
@@ -481,7 +476,7 @@ def run_cell(
     t0 = time.time()  # noqa: CIM201 timing
     try:
         fn, args, in_sh, out_sh, jkw = builder(cfg, shape, mesh)
-        with mesh:
+        with jax.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                              **jkw)
             lowered = jitted.lower(*args)

@@ -9,30 +9,16 @@ multi-pod adds a leading 'pod' axis for 2 x 256 = 512 chips. The model
 axis stays within a pod (ICI); the pod axis carries only data-parallel
 gradient reductions (DCN-friendly), which is where the int8 gradient
 compression applies.
-
-``jax.sharding.AxisType`` is jax>=0.5 only; on the container's jax
-0.4.37 every mesh axis is implicitly Auto, so the explicit annotation
-is simply dropped (same compat treatment ``distributed.sharding`` got
-for ``get_abstract_mesh``).
 """
 
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - exercised on jax 0.4.x
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _mk_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

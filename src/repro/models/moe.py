@@ -126,10 +126,7 @@ def _constrain_expert_buffer(xe):
     e_ax = _greedy_axes(e, ("pod", "data"), mesh, used)
     spec = jax.sharding.PartitionSpec(
         _entry(g_ax), _entry(e_ax), None, None)
-    try:
-        return jax.lax.with_sharding_constraint(xe, spec)
-    except (ValueError, RuntimeError):
-        return xe
+    return jax.lax.with_sharding_constraint(xe, spec)
 
 
 def _dispatch_grouped(params, x2, top_p, top_e, mo: MoEConfig, dtype):
@@ -213,13 +210,14 @@ def _experts_dense_cim(params, x2, top_p, top_e, mo, policy, key):
         )  # [T]
         ek = None if key is None else jax.random.fold_in(key, e)
         eks = (None,) * 3 if ek is None else jax.random.split(ek, 3)
-        g = common.linear_apply({"w": params["gate"][e]}, x2, policy,
-                                key=eks[0])
-        u = common.linear_apply({"w": params["up"][e]}, x2, policy,
-                                key=eks[1])
+        # Expert e's slice of each bank (of every stored tensor of a
+        # planned bank).
+        w = {n: jax.tree.map(lambda a, e=e: a[e], params[n])
+             for n in ("gate", "up", "down")}
+        g = common.linear_apply({"w": w["gate"]}, x2, policy, key=eks[0])
+        u = common.linear_apply({"w": w["up"]}, x2, policy, key=eks[1])
         h = jax.nn.silu(g) * u
-        y = common.linear_apply({"w": params["down"][e]}, h, policy,
-                                key=eks[2])
+        y = common.linear_apply({"w": w["down"]}, h, policy, key=eks[2])
         out = out + w_e[:, None] * y
     return out
 

@@ -12,9 +12,11 @@ construction, so every prefill/decode step reuses precomputed weight
 codes/colsums/scales instead of re-quantizing the weight side per
 matmul -- the serving analogue of the paper's SRAM-resident weights.
 Under a CIM-mode policy the planned codes equal the per-call ones, so
-token streams are bit-identical to the unplanned engine (tested); under
-an 'fp' policy planning instead means digital int8 weight-only serving
-(plans drop the float weights for the HBM-traffic win).
+token streams are bit-identical to the unplanned engine (tested, and
+on a TPU at bfloat16 activations by ``chip_smoke.py``; the steps
+compile with every declared dtype honoured, see ``_EXACT_DTYPES``);
+under an 'fp' policy planning instead means digital int8 weight-only
+serving (plans drop the float weights for the HBM-traffic win).
 
 Planned trees persist through ``checkpoint.store`` (PlannedWeights is a
 registered dataclass, so its leaves checkpoint under attribute paths):
@@ -34,10 +36,17 @@ Plan-aware scaling:
   memory-bound single-host CPU serving, where non-donated jit inputs
   are already zero-copy.
 * **Sharded planes** — ``mesh=`` places the planned tree under
-  ``distributed.sharding.shard_planned``: every stored-weight tensor
-  (codes, epilogue vectors, packed/unpacked ``planes``) is tensor-
-  parallel over the model axis on its output-channel dim, so planned
-  decode scales across devices without re-planning.
+  ``distributed.sharding.shard_planned`` (codes, kept fp weights and
+  packed/unpacked ``planes`` split over the model axis on their
+  output-channel dim) and runs each step under
+  ``distributed.sharding.per_device``: each device computes its own
+  output columns of every macro matmul and all-gathers the integer
+  result; every other read of a plan (fp matmuls, the MoE expert banks,
+  mamba's direct projections) gathers the weights. Everything else
+  (epilogue, norms, attention, caches) runs on whole tensors, repeated
+  on each device: the one-device program plus the gathers, so the
+  token stream equals the one-device engine's (tested). Mesh axes other
+  than 'model' split nothing.
 """
 
 from __future__ import annotations
@@ -52,6 +61,15 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import engine as cim_engine
 from repro.models import transformer
+
+# Serving steps compile with every declared dtype honoured. Allowed
+# excess precision, XLA keeps some bfloat16 intermediates in float32,
+# where its fusion decisions happen to let it; those differ between two
+# programs (planned vs unplanned, sharded vs one device), and a 4-bit
+# activation quantizer turns one rounding more or less into another
+# code. On a TPU v5e that parted planned and unplanned qwen2-0.5b token
+# streams at bfloat16 activations.
+_EXACT_DTYPES = {"xla_allow_excess_precision": False}
 
 
 class ServeEngine:
@@ -96,38 +114,37 @@ class ServeEngine:
             cfg, batch, max_len,
             dtype=jnp.dtype(cfg.activation_dtype),
         )
-        self._prefill = jax.jit(
-            lambda p, t, c: transformer.prefill(p, t, c, cfg)
-        )
+
+        def prefill(p, t, c):
+            return transformer.prefill(p, t, c, cfg)
+
+        def decode(p, tok, pos, c):
+            return transformer.decode_step(p, tok, pos, c, cfg)
+
+        if mesh is not None:
+            prefill = sharding.per_device(prefill, params, mesh)
+            decode = sharding.per_device(decode, params, mesh)
+        self._prefill = jax.jit(prefill, compiler_options=_EXACT_DTYPES)
         if donate_plan:
             # The decode step returns the (unchanged) params so XLA
             # aliases the donated plan buffers input->output; the
             # caches stay donated as before. self.params MUST be
             # rebound from the step's third output (_decode_step).
             self._decode = jax.jit(
-                lambda p, tok, pos, c: transformer.decode_step(
-                    p, tok, pos, c, cfg
-                ) + (p,),
-                donate_argnums=(0, 3),
+                lambda p, tok, pos, c: decode(p, tok, pos, c) + (p,),
+                donate_argnums=(0, 3), compiler_options=_EXACT_DTYPES,
             )
         else:
-            self._decode = jax.jit(
-                lambda p, tok, pos, c: transformer.decode_step(
-                    p, tok, pos, c, cfg
-                ),
-                donate_argnums=(3,),
-            )
+            self._decode = jax.jit(decode, donate_argnums=(3,),
+                                   compiler_options=_EXACT_DTYPES)
 
     def _decode_step(self, tok, pos):
         """One decode step, rebinding the donated plan buffers."""
+        out = self._decode(self.params, tok, pos, self.caches)
         if self._donate_plan:
-            logits, self.caches, self.params = self._decode(
-                self.params, tok, pos, self.caches
-            )
+            logits, self.caches, self.params = out
         else:
-            logits, self.caches = self._decode(
-                self.params, tok, pos, self.caches
-            )
+            logits, self.caches = out
         return logits
 
     @classmethod

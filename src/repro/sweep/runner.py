@@ -203,6 +203,21 @@ class RunReport:
         return self.finalized
 
 
+def _check_jobs(jobs: int) -> None:
+    """Refuse worker processes on a TPU host: the chip belongs to one
+    process, and every spawned worker imports JAX and would claim it."""
+    if jobs <= 1:
+        return
+    import jax  # noqa: PLC0415 - the sweep package imports JAX lazily
+
+    if jax.default_backend() == "tpu":
+        raise ValueError(
+            f"--jobs {jobs} on a TPU backend: each worker process would "
+            "claim the chip, which belongs to one process at a time; "
+            "run the sweep with --jobs 1"
+        )
+
+
 def run(
     config: SweepConfig,
     *,
@@ -217,6 +232,7 @@ def run(
     deterministic stand-in for "killed mid-run" in tests and a way to
     chunk long sweeps.
     """
+    _check_jobs(jobs)
     points = plan_lib.expand(config)
     config.sweep_dir.mkdir(parents=True, exist_ok=True)
     existing = read_points(config)

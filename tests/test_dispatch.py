@@ -207,7 +207,7 @@ class TestRouting:
         dispatch: it falls back to scan AND records the fallback;
         an explicit request still raises."""
         def boom(xc, wc, spec, *, key=None, planes=None, block=None):
-            raise ValueError("infeasible at this shape")
+            raise dispatch.KernelInfeasible("infeasible at this shape")
 
         kk = dispatch.register_kernel(
             dispatch.KernelKey("p8t", "boom"), boom
@@ -387,7 +387,7 @@ class TestAutotune:
     def test_infeasible_candidates_skipped(self):
         """A candidate that raises (depth guard etc.) is never a winner."""
         def boom(xc, wc, spec, *, key=None, planes=None, block=None):
-            raise ValueError("infeasible")
+            raise dispatch.KernelInfeasible("infeasible")
 
         key = dispatch.register_kernel(
             dispatch.KernelKey("p8t", "boom"), boom

@@ -268,8 +268,13 @@ class TestDecodeBlocks:
                 for bm, bn, bk in blocks:
                     assert bm in autotune.DECODE_BMS
                     assert bk % rows == 0, (rows, bk)
+                    # TPU (8, 128) block rule: bm=1 only when m == 1.
+                    if m == 1:
+                        assert bm == 1
+                        continue
+                    assert bm % 8 == 0, (m, bm)
                     if m is not None:
-                        cap = 1
+                        cap = 8
                         while cap < m and cap < max(autotune.DECODE_BMS):
                             cap *= 2
                         assert bm <= cap

@@ -270,15 +270,17 @@ def test_every_registered_kernel_key_matches_oracle(
 @given(
     t_scan=st.floats(0.1, 10.0),
     t_ref=st.floats(0.1, 10.0),
+    t_slots=st.floats(0.1, 10.0),
     m=st.sampled_from([4, 8, 32]),
     seed=st.integers(0, 2**31 - 1),
 )
 @settings(max_examples=10, deadline=None)
-def test_tuning_cache_round_trip_determinism(t_scan, t_ref, m, seed):
+def test_tuning_cache_round_trip_determinism(t_scan, t_ref, t_slots, m, seed):
     """Same sweep -> same pinned winners, and the JSON cache round-trips
     losslessly (the deterministic re-load path dispatch consults)."""
     del seed  # shapes/measure fully determine the sweep
-    times = {"scan": t_scan, "ref": t_ref, "pallas": 99.0}
+    # One fake time per candidate backend default_candidates enumerates.
+    times = {"scan": t_scan, "ref": t_ref, "slots": t_slots, "pallas": 99.0}
 
     def measure(cand, run):
         run()

@@ -140,3 +140,80 @@ def test_donated_and_sharded_plan_decode_parity():
     np.testing.assert_array_equal(out, sharded.generate(prompts, 6))
     # the caller's tree survived the donations (engines copied it)
     jax.tree.map(lambda x: np.asarray(x).sum(), params)
+
+
+_FOUR_DEVICE_SCRIPT = """
+import sys
+import jax, numpy as np
+from repro.configs.base import CIMPolicy, get_config
+from repro.core.engine import PlannedWeights
+from repro.core.params import PAPER_OP_16ROWS
+from repro.launch.mesh import make_host_mesh
+from repro.models import transformer
+from repro.serve.engine import ServeEngine
+
+arch, must_split = sys.argv[1], sys.argv[2].split(",")
+mesh = make_host_mesh((1, 4))
+for policy in (CIMPolicy(mode="cim", cim=PAPER_OP_16ROWS), CIMPolicy()):
+    cfg = get_config(arch, smoke=True).replace(
+        activation_dtype="float32", cim=policy)
+    if arch == "qwen2_0_5b":
+        # 14 heads and 2 kv heads as in qwen2-0.5b: the head split does
+        # not divide the 4-way model axis, only the weight columns do.
+        cfg = cfg.replace(n_heads=14, n_kv_heads=2, d_model=224,
+                          head_dim=16, d_ff=256, n_layers=6)
+    params = transformer.init(jax.random.PRNGKey(0), cfg)
+    prompts = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
+                                 cfg.vocab_size)
+    kw = dict(max_len=32, batch=4, plan=True)
+    sharded = ServeEngine(params, cfg, mesh=mesh, **kw)
+    one = ServeEngine(params, cfg, **kw)
+    split = set()
+    for path, p in jax.tree_util.tree_flatten_with_path(
+            sharded.params,
+            is_leaf=lambda x: isinstance(x, PlannedWeights))[0]:
+        if isinstance(p, PlannedWeights) and len(
+                {s.device for s in p.codes.addressable_shards}) == 4:
+            split.add(jax.tree_util.keystr(path))
+    for name in must_split:
+        assert any(name in k for k in split), (name, sorted(split))
+    caches = transformer.init_caches(cfg, 4, 32)
+    ls = np.asarray(sharded._prefill(sharded.params, prompts, caches)[0])
+    l1 = np.asarray(one._prefill(one.params, prompts, caches)[0])
+    # every read of a plan yields whole columns: the one-device program
+    # plus gathers, bit for bit
+    np.testing.assert_array_equal(ls, l1)
+    np.testing.assert_array_equal(sharded.generate(prompts, 4),
+                                  one.generate(prompts, 4))
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("arch, must_split", [
+    ("qwen2_0_5b", "['mlp']['gate']"),
+    ("qwen2_moe_a2_7b", "['moe']['gate'],['moe']['down']"),
+    ("jamba_1_5_large", "['moe']['up'],['x_proj'],['dt_proj']"),
+])
+def test_sharded_engine_matches_one_device_on_four_host_devices(
+        arch, must_split):
+    """``ServeEngine(mesh=)`` over a (1, 4) mesh of host devices (the
+    CPU rehearsal of the four-chip path), for a dense, an MoE and a
+    hybrid attention/mamba/MoE config: the named plans' weight columns
+    sit on 4 devices, and the prefill logits and greedy tokens equal
+    the one-device engine's bit for bit, under a CIM and an fp policy.
+    A fresh process, since the host device count is fixed when JAX
+    starts."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=f"{src}{os.pathsep}{os.environ.get('PYTHONPATH', '')}")
+    out = subprocess.run(
+        [sys.executable, "-c", _FOUR_DEVICE_SCRIPT, arch, must_split],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert out.stdout.strip().endswith("OK")

@@ -14,6 +14,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import ARCH_IDS, get_config
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_host_mesh
 from repro.models import transformer
 
 
@@ -115,14 +116,14 @@ class TestActivationConstraints:
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
     def test_constrain_under_real_mesh(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_host_mesh((1, 1))
         x = jnp.ones((4, 8, 16))
 
         @jax.jit
         def f(x):
             return shd.constrain(x, ("act_batch", "act_seq", "act_vocab"))
 
-        with mesh:
+        with jax.set_mesh(mesh):
             y = f(x)
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
@@ -169,14 +170,16 @@ class TestPlannedShardings:
         w = jnp.ones((64, 32), jnp.float32)
         for pack in (False, True):
             plan = cim.plan_weights(w, with_planes=True,
-                                    pack_planes=pack)
+                                    pack_planes=pack, with_slots=True)
             sh = shd.plan_shardings(plan, mesh)
             assert sh.codes.spec == P(None, "model")
-            assert sh.scale.spec == P(None, "model")
-            assert sh.colsum.spec == P(None, "model")
             assert sh.w.spec == P(None, "model")
             lead = (None,) * (plan.planes.ndim - 1)
             assert sh.planes.spec == P(*lead, "model")
+            # Epilogue vectors and the slot-major slots stay whole.
+            assert sh.scale.spec == P()
+            assert sh.colsum.spec == P()
+            assert sh.slots.spec == P()
 
     def test_tree_shardings_and_device_put(self):
         from repro.core import engine as cim

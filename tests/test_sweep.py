@@ -228,6 +228,17 @@ class TestRunnerResume:
         assert (par.points_path.read_bytes()
                 == serial.points_path.read_bytes())
 
+    def test_parallel_run_refused_on_tpu(self, tmp_path, monkeypatch):
+        """Worker processes would each claim the one chip: --jobs > 1 is
+        refused on a TPU backend before any point runs."""
+        import jax
+
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        cfg = echo_config(tmp_path)
+        with pytest.raises(ValueError, match="one process"):
+            runner.run(cfg, jobs=2, log=lambda _s: None)
+        assert not cfg.points_path.exists()
+
 
 class TestAnalysis:
     def test_table_renderer_is_deterministic(self, tmp_path):
