@@ -1,0 +1,7 @@
+"""Percent of the traced window with no device operation running (decode cell)."""
+
+from perfbench import readers
+
+
+def read(ctx):
+    return readers.idle_share(ctx)
