@@ -48,6 +48,8 @@ import traceback
 
 import numpy as np
 
+from perfbench.clock import CompileClock
+
 SEED = 0
 # qwen2-0.5b projections: q/o (896x896), gate/up (896x4864) and the
 # down projection (4864x896), whose K >= 4096 plans packed planes.
@@ -67,23 +69,6 @@ class SmokeFailure(Exception):
 def check(cond: bool, msg: str) -> None:
     if not cond:
         raise SmokeFailure(msg)
-
-
-class CompileClock:
-    """Sums JAX's trace, lowering and backend-compile durations."""
-
-    EVENTS = frozenset({
-        "/jax/core/compile/jaxpr_trace_duration",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        "/jax/core/compile/backend_compile_duration",
-    })
-
-    def __init__(self):
-        self.total = 0.0
-
-    def __call__(self, event: str, secs: float, **_):
-        if event in self.EVENTS:
-            self.total += secs
 
 
 @contextlib.contextmanager

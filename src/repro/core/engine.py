@@ -437,24 +437,31 @@ def quantized_backend(int_fn) -> BackendFn:
     """Wrap ``int_fn(x_codes, plan, cfg, key) -> y_int`` with the shared
     quantized-execution epilogue (the digital periphery of the macro):
     dynamic activation quantization in, dequantization + zero-point
-    column correction out."""
+    column correction out.
+
+    The three stages carry the device scopes ``cim.quantize``,
+    ``cim.macro`` and ``cim.epilogue`` (``jax.named_scope``: metadata
+    only), by which a profiler trace attributes their operations."""
 
     def run(x2, plan, policy, key):
         cfg = policy.cim
-        qa = quant.quantize_acts(
-            x2,
-            cfg.act_bits,
-            symmetric=policy.act_symmetric,
-            clip_pct=policy.act_clip_pct,
-        )
-        y_int = plan.whole_columns(int_fn(qa.codes, plan, cfg, key))
-        colsum = plan.colsum
-        if colsum is None:  # minimal plans: recover digitally (free)
-            colsum = plan.whole_columns(jnp.sum(
-                plan.codes_i32, axis=-2, keepdims=True
-            ).astype(jnp.float32))
-        y = y_int - qa.zero_point.astype(jnp.float32) * colsum
-        return y * qa.scale * plan.scale
+        with jax.named_scope("cim.quantize"):
+            qa = quant.quantize_acts(
+                x2,
+                cfg.act_bits,
+                symmetric=policy.act_symmetric,
+                clip_pct=policy.act_clip_pct,
+            )
+        with jax.named_scope("cim.macro"):
+            y_int = plan.whole_columns(int_fn(qa.codes, plan, cfg, key))
+        with jax.named_scope("cim.epilogue"):
+            colsum = plan.colsum
+            if colsum is None:  # minimal plans: recover digitally (free)
+                colsum = plan.whole_columns(jnp.sum(
+                    plan.codes_i32, axis=-2, keepdims=True
+                ).astype(jnp.float32))
+            y = y_int - qa.zero_point.astype(jnp.float32) * colsum
+            return y * qa.scale * plan.scale
 
     return run
 
@@ -535,7 +542,8 @@ def execute(
     y = fn(x2, plan, policy, key)
     y = y.reshape(*orig_shape[:-1], plan.n)
     if policy.mode != "fp":
-        y = y.astype(x.dtype)
+        with jax.named_scope("cim.epilogue"):
+            y = y.astype(x.dtype)
     return y
 
 

@@ -373,7 +373,7 @@ def dispatch(
 
         return engine.regroup_planes(planes, k, spec.rows_active)
 
-    def run(chosen: KernelImpl, blk):
+    def run(chosen: KernelImpl, name: str, blk):
         kwargs: dict[str, Any] = dict(
             key=key if chosen.supports_noise else None,
             planes=planes_for(chosen),
@@ -381,12 +381,15 @@ def dispatch(
         )
         if chosen.supports_slots:
             kwargs["slots"] = slots
-        return chosen.fn(x_codes, w_codes, spec, **kwargs)
+        # The backend that ran names the device scope of its operations
+        # (``cim.macro/scan`` inside the engine's macro scope).
+        with jax.named_scope(name):
+            return chosen.fn(x_codes, w_codes, spec, **kwargs)
 
     if source == "explicit" or backend == "scan":
-        return run(impl, block)
+        return run(impl, backend, block)
     try:
-        return run(impl, block)
+        return run(impl, backend, block)
     except KernelInfeasible:
         # Implicitly-chosen impl infeasible at this shape/operating
         # point (e.g. the Pallas f32 depth guard, a stale tuned pin):
@@ -400,7 +403,7 @@ def dispatch(
             key=KernelKey(variant, "scan", cell, dtype),
             source="guard-fallback",
         ))
-        return run(scan, None)
+        return run(scan, "scan", None)
 
 
 # ---------------------------------------------------------------------------

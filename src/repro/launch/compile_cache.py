@@ -23,7 +23,13 @@ def enable_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR``, when set, is left as it is: JAX reads
     it itself and no other directory is set here. Otherwise the cache
     goes to ``<repo>/.jax_cache`` (git-ignored).
+
+    The key includes each operation's metadata, its name scope. JAX
+    leaves it out by default, and a program loaded from the cache then
+    names its operations as an earlier source with the same operations
+    did: a profile of this source would show another's scopes.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -11,6 +11,9 @@ Cache layouts:
                 offsets survive the ring indexing).
 Decode is one query token against the cache; prefill writes the cache in
 bulk and runs the masked quadratic core.
+
+Device scopes (``jax.named_scope``, metadata only): ``attn.core`` on the
+QK/softmax/PV cores, ``attn.cache`` on the K/V cache writes.
 """
 
 from __future__ import annotations
@@ -74,6 +77,7 @@ def _project_qkv(params, x, cfg: ModelConfig, policy: CIMPolicy | None,
     return q, k, v
 
 
+@jax.named_scope("attn.core")
 def _gqa_core(
     q: jax.Array,  # [B, S, H, hd]
     k: jax.Array,  # [B, T, KVH, hd]
@@ -96,6 +100,7 @@ def _gqa_core(
     return out.reshape(b, s, h, hd).astype(q.dtype)
 
 
+@jax.named_scope("attn.core")
 def _flash_core(
     q: jax.Array,  # [B, S, H, hd]
     k: jax.Array,  # [B, T, KVH, hd]
@@ -243,22 +248,23 @@ def prefill_cache(
     q = constrain_query(common.apply_rope(q, positions, cfg.rope_theta))
     k = common.apply_rope(k, positions, cfg.rope_theta)
     c = cache.k.shape[1]
-    kc = k.astype(cache.k.dtype)  # cache may be fp8 (storage dtype)
-    vc = v.astype(cache.v.dtype)
-    if window and c == window:
-        # Keep the last `window` tokens, slot = pos % window.
-        take = min(s, window)
-        idx = (positions[:, -take:] % window).astype(jnp.int32)
-        bidx = jnp.arange(b)[:, None]
-        new_k = cache.k.at[bidx, idx].set(kc[:, -take:])
-        new_v = cache.v.at[bidx, idx].set(vc[:, -take:])
-    else:
-        new_k = jax.lax.dynamic_update_slice(
-            cache.k, kc, (0, 0, 0, 0)
-        )
-        new_v = jax.lax.dynamic_update_slice(
-            cache.v, vc, (0, 0, 0, 0)
-        )
+    with jax.named_scope("attn.cache"):
+        kc = k.astype(cache.k.dtype)  # cache may be fp8 (storage dtype)
+        vc = v.astype(cache.v.dtype)
+        if window and c == window:
+            # Keep the last `window` tokens, slot = pos % window.
+            take = min(s, window)
+            idx = (positions[:, -take:] % window).astype(jnp.int32)
+            bidx = jnp.arange(b)[:, None]
+            new_k = cache.k.at[bidx, idx].set(kc[:, -take:])
+            new_v = cache.v.at[bidx, idx].set(vc[:, -take:])
+        else:
+            new_k = jax.lax.dynamic_update_slice(
+                cache.k, kc, (0, 0, 0, 0)
+            )
+            new_v = jax.lax.dynamic_update_slice(
+                cache.v, vc, (0, 0, 0, 0)
+            )
     out = _self_attention_core(q, k, v, positions=positions[0],
                                window=window, s=s)
     en = policy.apply_to_attn_proj if policy else False
@@ -288,23 +294,19 @@ def decode_step(
     k = common.apply_rope(k, positions, cfg.rope_theta)
 
     c = cache.k.shape[1]
-    kc = k.astype(cache.k.dtype)  # cache may be fp8 (storage dtype)
-    vc = v.astype(cache.v.dtype)
-    if window and c == window:
-        slot = (pos % window).astype(jnp.int32)
-        new_k = jax.lax.dynamic_update_slice(
-            cache.k, kc, (0, slot, 0, 0))
-        new_v = jax.lax.dynamic_update_slice(
-            cache.v, vc, (0, slot, 0, 0))
+    ring = window and c == window
+    with jax.named_scope("attn.cache"):
+        kc = k.astype(cache.k.dtype)  # cache may be fp8 (storage dtype)
+        vc = v.astype(cache.v.dtype)
+        slot = (pos % window if ring else pos).astype(jnp.int32)
+        new_k = jax.lax.dynamic_update_slice(cache.k, kc, (0, slot, 0, 0))
+        new_v = jax.lax.dynamic_update_slice(cache.v, vc, (0, slot, 0, 0))
+    if ring:
         # Slots 0..pos valid until the ring wraps; afterwards every slot
         # holds one of the last `window` tokens.
         valid = (jnp.arange(c)[None, :] < pos + 1) | (pos + 1 >= c)
         mask = valid[None, None, None, :]
     else:
-        new_k = jax.lax.dynamic_update_slice(
-            cache.k, kc, (0, pos.astype(jnp.int32), 0, 0))
-        new_v = jax.lax.dynamic_update_slice(
-            cache.v, vc, (0, pos.astype(jnp.int32), 0, 0))
         mask = (jnp.arange(c) <= pos)[None, None, None, None, :]
 
     out = _gqa_core(q, new_k, new_v, mask)

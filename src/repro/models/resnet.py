@@ -14,6 +14,10 @@ forward — mirroring the macro, whose SRAM weights are written once.
 
 Functional with explicit BatchNorm state:
   forward(params, bn_state, x, cfg, train) -> (logits, new_bn_state)
+
+Device scopes (``jax.named_scope``, metadata only): ``resnet.im2col``,
+``resnet.bn``, ``resnet.pool``; the macro convs carry the engine's
+``cim.*`` scopes.
 """
 
 from __future__ import annotations
@@ -156,12 +160,13 @@ def _conv(params_w, x, stride, policy: CIMPolicy | None,
                 x, params_w, (stride, stride), "SAME",
                 dimension_numbers=("NHWC", "HWIO", "NHWC"),
             )
-    patches = jax.lax.conv_general_dilated_patches(
-        x, tuple(kernel_hw), (stride, stride), "SAME",
-        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-    )  # [B, Ho, Wo, cin*kh*kw] (channel-major patch layout)
-    b, ho, wo, pf = patches.shape
-    x2 = patches.reshape(-1, pf)
+    with jax.named_scope("resnet.im2col"):
+        patches = jax.lax.conv_general_dilated_patches(
+            x, tuple(kernel_hw), (stride, stride), "SAME",
+            dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        )  # [B, Ho, Wo, cin*kh*kw] (channel-major patch layout)
+        b, ho, wo, pf = patches.shape
+        x2 = patches.reshape(-1, pf)
     if planned:
         plan = params_w.plan
         assert plan.k == pf, (plan.k, pf, kernel_hw)
@@ -218,6 +223,7 @@ def plan_params(params: dict, policy: CIMPolicy) -> dict:
     return walk(params)
 
 
+@jax.named_scope("resnet.bn")
 def _bn(params, state, x, train: bool, momentum: float):
     if train:
         mu = jnp.mean(x, axis=(0, 1, 2))
@@ -284,7 +290,8 @@ def forward(
             new_state[name] = ns
             cin = cout
 
-    h = jnp.mean(h, axis=(1, 2))  # global average pool
+    with jax.named_scope("resnet.pool"):
+        h = jnp.mean(h, axis=(1, 2))  # global average pool
     logits = common.linear_apply(params["fc"], h, policy,
                                  cim_enabled=policy.apply_to_logits,
                                  key=nk())

@@ -14,6 +14,9 @@ Entry points:
   init_caches / prefill / decode_step -> serving path
 Encoder-decoder (whisper) adds encode() and uses cross-attention in the
 decoder; VLM/audio frontends are embedding stubs per the assignment.
+
+Device scopes (``jax.named_scope``, metadata only): ``lm.norm`` on every
+RMSNorm, ``lm.head`` on the final norm and the logits.
 """
 
 from __future__ import annotations
@@ -152,6 +155,11 @@ class LayerAux(NamedTuple):
     moe_aux: jax.Array
 
 
+def _norm(p, x: jax.Array, cfg: ModelConfig) -> jax.Array:
+    with jax.named_scope("lm.norm"):
+        return common.rmsnorm_apply(p, x, cfg.norm_eps)
+
+
 def _layer_apply(
     lp: Params,
     x: jax.Array,
@@ -164,7 +172,7 @@ def _layer_apply(
     memory_kv=None,
 ) -> tuple[jax.Array, jax.Array]:
     kind = cfg.layer_kind(layer_idx)
-    h = common.rmsnorm_apply(lp["norm1"], x, cfg.norm_eps)
+    h = _norm(lp["norm1"], x, cfg)
     if kind in ("attn", "attn_local"):
         window = cfg.window_size if kind == "attn_local" else 0
         a = attention.attend_full(
@@ -179,11 +187,11 @@ def _layer_apply(
     x = x + a.astype(x.dtype)
 
     if memory_kv is not None and "xattn" in lp:
-        hx = common.rmsnorm_apply(lp["norm_x"], x, cfg.norm_eps)
+        hx = _norm(lp["norm_x"], x, cfg)
         x = x + attention.cross_attend(lp["xattn"], hx, memory_kv, cfg,
                                        policy=policy, key=key)
 
-    h = common.rmsnorm_apply(lp["norm2"], x, cfg.norm_eps)
+    h = _norm(lp["norm2"], x, cfg)
     aux = jnp.zeros((), jnp.float32)
     if kind == "rwkv":
         m, _ = rwkv.channelmix_apply(lp["cm"], h, cfg, policy=policy,
@@ -229,20 +237,22 @@ def _embed_inputs(params, batch, cfg: ModelConfig):
 
 
 def _logits(params, x, cfg: ModelConfig, policy: CIMPolicy | None):
-    h = common.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        table = params["embed"]["table"].astype(h.dtype)
-        logits = jnp.einsum("bsd,vd->bsv", h, table)
-    else:
-        en = policy.apply_to_logits if policy else False
-        logits = common.linear_apply(params["lm_head"], h, policy,
-                                     cim_enabled=en)
-    if cfg.padded_vocab != cfg.vocab_size:
-        # Vocab-pad columns never win argmax nor enter the softmax mass.
-        pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
-        logits = jnp.where(pad_mask, jnp.asarray(-1e30, logits.dtype),
-                           logits)
-    return constrain(logits, ("act_batch", "act_seq", "act_vocab"))
+    with jax.named_scope("lm.head"):
+        h = _norm(params["final_norm"], x, cfg)
+        if cfg.tie_embeddings:
+            table = params["embed"]["table"].astype(h.dtype)
+            logits = jnp.einsum("bsd,vd->bsv", h, table)
+        else:
+            en = policy.apply_to_logits if policy else False
+            logits = common.linear_apply(params["lm_head"], h, policy,
+                                         cim_enabled=en)
+        if cfg.padded_vocab != cfg.vocab_size:
+            # Vocab-pad columns never win argmax nor enter the softmax
+            # mass.
+            pad_mask = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
+            logits = jnp.where(pad_mask, jnp.asarray(-1e30, logits.dtype),
+                               logits)
+        return constrain(logits, ("act_batch", "act_seq", "act_vocab"))
 
 
 def encode(params, frames: jax.Array, cfg: ModelConfig,
@@ -258,16 +268,16 @@ def encode(params, frames: jax.Array, cfg: ModelConfig,
                           layer_pattern=("attn",), moe=None)
     for j in range(cfg.n_encoder_layers):
         lp = params["encoder"][f"enc_{j:02d}"]
-        h = common.rmsnorm_apply(lp["norm1"], x, cfg.norm_eps)
+        h = _norm(lp["norm1"], x, cfg)
         # Bidirectional: full (non-causal) window = whole sequence.
         q, k, v = attention._project_qkv(lp["attn"], h, enc_cfg, policy)
         a = attention._gqa_core(q, k, v, None)
         a = common.linear_apply(
             lp["attn"]["wo"], a.reshape(b, s, enc_cfg.q_dim), policy)
         x = x + a
-        h = common.rmsnorm_apply(lp["norm2"], x, cfg.norm_eps)
+        h = _norm(lp["norm2"], x, cfg)
         x = x + common.mlp_apply(lp["mlp"], h, cfg.mlp_act, policy)
-    return common.rmsnorm_apply(params["enc_norm"], x, cfg.norm_eps)
+    return _norm(params["enc_norm"], x, cfg)
 
 
 def forward_train(
@@ -421,7 +431,7 @@ def _layer_prefill(
 ):
     """Forward over the prompt while populating this layer's cache."""
     kind = cfg.layer_kind(layer_idx)
-    h = common.rmsnorm_apply(lp["norm1"], x, cfg.norm_eps)
+    h = _norm(lp["norm1"], x, cfg)
     if kind in ("attn", "attn_local"):
         window = cfg.window_size if kind == "attn_local" else 0
         a, cache = attention.prefill_cache(
@@ -443,10 +453,10 @@ def _layer_prefill(
         )
     x = x + a.astype(x.dtype)
     if memory_kv is not None and "xattn" in lp:
-        hx = common.rmsnorm_apply(lp["norm_x"], x, cfg.norm_eps)
+        hx = _norm(lp["norm_x"], x, cfg)
         x = x + attention.cross_attend(lp["xattn"], hx, memory_kv, cfg,
                                        policy=policy)
-    h = common.rmsnorm_apply(lp["norm2"], x, cfg.norm_eps)
+    h = _norm(lp["norm2"], x, cfg)
     if kind == "rwkv":
         m, s_cm = rwkv.channelmix_apply(lp["cm"], h, cfg, policy=policy)
         cache = cache._replace(shift_cm=s_cm.astype(cache.shift_cm.dtype))
@@ -547,7 +557,7 @@ def _layer_decode(
     memory_kv=None,
 ):
     kind = cfg.layer_kind(layer_idx)
-    h = common.rmsnorm_apply(lp["norm1"], x, cfg.norm_eps)
+    h = _norm(lp["norm1"], x, cfg)
     if kind in ("attn", "attn_local"):
         window = cfg.window_size if kind == "attn_local" else 0
         a, cache = attention.decode_step(lp["attn"], h, cfg, cache, pos,
@@ -567,10 +577,10 @@ def _layer_decode(
         )
     x = x + a.astype(x.dtype)
     if memory_kv is not None and "xattn" in lp:
-        hx = common.rmsnorm_apply(lp["norm_x"], x, cfg.norm_eps)
+        hx = _norm(lp["norm_x"], x, cfg)
         x = x + attention.cross_attend(lp["xattn"], hx, memory_kv, cfg,
                                        policy=policy)
-    h = common.rmsnorm_apply(lp["norm2"], x, cfg.norm_eps)
+    h = _norm(lp["norm2"], x, cfg)
     if kind == "rwkv":
         m, s_cm = rwkv.channelmix_apply(
             lp["cm"], h.astype(cache.shift_cm.dtype), cfg,

@@ -61,6 +61,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core import engine as cim_engine
 from repro.models import transformer
+from repro.serve.spans import span
 
 # Serving steps compile with every declared dtype honoured. Allowed
 # excess precision, XLA keeps some bfloat16 intermediates in float32,
@@ -186,19 +187,29 @@ class ServeEngine:
                    calibration=calibration)
 
     def generate(self, prompts: jax.Array, n_tokens: int) -> np.ndarray:
-        """Greedy-decode n_tokens after the prompt batch [B, S]."""
+        """Greedy-decode n_tokens after the prompt batch [B, S].
+
+        Host spans (``serve.spans``: in the profiler's trace, and kept
+        in ``spans.RECENT``): ``serve.generate`` over the call,
+        ``serve.prefill`` and one ``serve.decode`` per step around each
+        dispatch, and ``serve.fetch`` around the one device-to-host
+        copy."""
         b, s = prompts.shape
         assert b == self.batch
-        logits, self.caches = self._prefill(self.params, prompts,
-                                            self.caches)
-        tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out = [tok]
-        for i in range(n_tokens - 1):
-            pos = jnp.asarray(s + i, dtype=jnp.int32)
-            logits = self._decode_step(tok, pos)
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out.append(tok)
-        return np.stack([np.asarray(t) for t in out], axis=1)
+        with span("serve.generate", batch=b, prompt_len=s, n_tokens=n_tokens):
+            with span("serve.prefill"):
+                logits, self.caches = self._prefill(self.params, prompts,
+                                                    self.caches)
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            out = [tok]
+            for i in range(n_tokens - 1):
+                with span("serve.decode", step=i):
+                    pos = jnp.asarray(s + i, dtype=jnp.int32)
+                    logits = self._decode_step(tok, pos)
+                    tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                out.append(tok)
+            with span("serve.fetch", arrays=len(out)):
+                return np.stack([np.asarray(t) for t in out], axis=1)
 
 
 @dataclasses.dataclass
