@@ -85,9 +85,10 @@ def decode_blocks(
 ) -> tuple[tuple[int, int, int], ...]:
     """Decode-shape Pallas tiling candidates.
 
-    The default 128-row M tiles pad an m=1 decode step to 128 rows and
-    burn 128x the FLOPs; these candidates pair small bm values
-    (``DECODE_BMS``) with bk values aligned to the calibration's
+    A 128-row M tile pads an m=1 decode step to 128 rows and burns
+    128x the FLOPs (the default block, ``dispatch._pallas_blocks``,
+    already follows M down to 8 rows); these candidates pair small bm
+    values (``DECODE_BMS``) with bk values aligned to the calibration's
     ``rows_active`` group (the kernel requires rows | bk, and a
     rows-aligned bk avoids the dispatch adapter's round-down losing
     contraction depth for non-power-of-two rows).

@@ -28,6 +28,14 @@ Tiling (BlockSpec):
                       so both storage forms lower through one kernel
   out tile [bm, bn]   f32 accumulated shift-add results
 
+  ``kernels.dispatch`` sizes the tile to the call when no block is
+  pinned: bm = min(128, M rounded up to 8), so a batch-32 decode step
+  runs 32-row tiles instead of 128 rows of which 96 are padding; bn
+  widens (256, 512) only as bm shrinks, keeping bm * bn <= 128 * 128
+  (the out/ADC tile never grows); bk = 128 rounded down to a multiple
+  of rows_active. The result does not depend on the tiling: each
+  output accumulates the same (group, plane) codes in the same k order.
+
 Inside one k step the kernel extracts the B two's-complement planes of
 the w tile as [bk, bn] 0/1 tiles and runs, per 16-row group g and plane
 b, one MXU contraction

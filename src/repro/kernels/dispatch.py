@@ -319,7 +319,9 @@ def dispatch(
         Dropped when grouped at a different rows_active (slots cannot
         be regrouped); the "slots" backend requires it.
       block: (bm, bn, bk) Pallas tiling override; defaults to the
-        tuned winner's blocks, else (128, 128, 128).
+        tuned winner's blocks, else a tile sized to the call
+        (``_pallas_blocks``: bm follows M up to 128, bn widens as bm
+        shrinks). The logged ``Resolution.block`` is the block that ran.
     """
     spec = as_spec(spec)
     m, k = x_codes.shape
@@ -356,6 +358,8 @@ def dispatch(
             f"registered backends for this variant: "
             f"{backends_for(variant)}"
         )
+    if impl.is_pallas:
+        block = _pallas_blocks(spec, block, m, n)
     _notify(Resolution(
         key=KernelKey(variant, backend, cell, dtype),
         source=source,
@@ -432,10 +436,33 @@ def _ref_impl(module, attr: str) -> KernelFn:
     return run
 
 
+# The default rule's largest output tile, in elements: the [bm, bn] f32
+# accumulator and ADC tile that lives in VMEM. A shorter M tile may
+# widen N (from _WIDE_BNS) up to it, never past it.
+_TILE_ELEMS = 128 * 128
+_WIDE_BNS = (128, 256, 512)
+
+
 def _pallas_blocks(
-    spec: MacroSpec, block: tuple[int, int, int] | None
+    spec: MacroSpec, block: tuple[int, int, int] | None, m: int, n: int
 ) -> tuple[int, int, int]:
-    bm, bn, bk = block or (128, 128, 128)
+    """The (bm, bn, bk) one Pallas call of shape [m, K] x [K, n] runs at.
+
+    An explicit or tuned ``block`` is taken as given. Otherwise the M
+    tile follows the call: ``bm = min(128, round_up(m, 8))`` (a TPU
+    block's second-minor dim is a multiple of 8; ``_tiled_call`` pads
+    x to whole tiles), so an m=32 decode step computes 32 rows, not 128
+    of which 96 are padding. ``bn`` is the widest of ``_WIDE_BNS`` with
+    ``bm * bn <= _TILE_ELEMS``, capped at n rounded up to 128: the same
+    tile for m >= 128, fewer grid steps below. Either way bk rounds
+    down to a multiple of ``rows_active`` (the kernel needs rows | bk).
+    """
+    if block is None:
+        bm = min(128, -(-m // 8) * 8)
+        n_cap = -(-n // 128) * 128
+        bn = max(b for b in _WIDE_BNS if bm * b <= _TILE_ELEMS and b <= n_cap)
+        block = (bm, bn, 128)
+    bm, bn, bk = block
     rows = spec.rows_active
     bk = max(rows, bk - bk % rows)  # kernel needs rows | bk
     return bm, bn, bk
@@ -470,7 +497,7 @@ def _pallas_impl(kernel_name: str) -> KernelFn:
             # here), so the resident int8 codes never re-load.
             k = x_codes.shape[1]
             w_codes = planes.reshape(-1, planes.shape[-1])[:k]
-        bm, bn, bk = _pallas_blocks(spec, block)
+        bm, bn, bk = block  # resolved by dispatch (_pallas_blocks)
         fn = getattr(ops, kernel_name)
         return fn(x_codes, w_codes, spec, bm=bm, bn=bn, bk=bk)
 

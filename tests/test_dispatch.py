@@ -315,6 +315,96 @@ class TestRouting:
             engine._BACKENDS.pop("dispatch-route-test", None)
 
 
+class TestDefaultBlock:
+    """The Pallas tile follows the call's M when no block is pinned."""
+
+    @pytest.mark.parametrize("variant", VARIANTS)
+    @pytest.mark.parametrize("m", [1, 8, 32, 40, 128, 200])
+    def test_default_block_bit_exact(self, variant, m):
+        """At every M tile the output equals the (128, 128, 128) tile's
+        and the variant's oracle: only padding rows stop being computed.
+        k is not a multiple of 128, n not of the widened bn."""
+        cfg = PAPER_OP_16ROWS
+        k, n = 200, 300
+        x, w = rand_codes(m, k, n, cfg)
+        _, bn, _ = dispatch._pallas_blocks(dispatch.as_spec(cfg), None, m, n)
+        assert n % bn, bn  # the last N tile is ragged at every bn
+        default = dispatch.dispatch(x, w, cfg, variant=variant,
+                                    backend="pallas")
+        full = dispatch.dispatch(x, w, cfg, variant=variant,
+                                 backend="pallas", block=(128, 128, 128))
+        want = np.asarray(scan_oracle(variant, x, w, cfg))
+        np.testing.assert_array_equal(np.asarray(default),
+                                      np.asarray(full))
+        np.testing.assert_array_equal(np.asarray(default), want)
+
+    @pytest.mark.parametrize("rows", [8, 12, 16])
+    def test_block_rule(self, rows):
+        spec = dispatch.as_spec(PAPER_OP_16ROWS.replace(rows_active=rows))
+        for m in (1, 2, 7, 8, 9, 31, 32, 33, 40, 64, 100, 127, 128, 129,
+                  200, 2048, 4096):
+            for n in (1, 100, 128, 300, 896, 4864):
+                bm, bn, bk = dispatch._pallas_blocks(spec, None, m, n)
+                assert bm == (128 if m >= 128 else -(-m // 8) * 8), (m, n)
+                assert bk % rows == 0 and bk <= 128, (m, n)
+                assert bm * bn <= 128 * 128, (m, n)
+                assert bn % 128 == 0 and bn <= -(-n // 128) * 128, (m, n)
+                if m >= 128:
+                    assert bn == 128, (m, n)
+
+    def test_explicit_block_wins(self):
+        spec = dispatch.as_spec(PAPER_OP_16ROWS)
+        assert dispatch._pallas_blocks(spec, (64, 128, 128), 32, 896) == (
+            64, 128, 128)
+        # bk still rounds down to a multiple of rows_active
+        assert dispatch._pallas_blocks(spec, (8, 128, 100), 1, 896) == (
+            8, 128, 96)
+
+    def test_logged_block_is_the_block_that_ran(self, monkeypatch):
+        """Under record_resolutions the Pallas route logs the block the
+        kernel ran at: the rule's, an explicit one, a tuned pin's, and
+        on the heuristic route (the chip's) the rule's again."""
+        from repro.kernels import ops
+
+        ran = []
+
+        def spy(x_codes, w_codes, cfg, *, bm, bn, bk):
+            ran.append((bm, bn, bk))
+            return jnp.zeros((x_codes.shape[0], w_codes.shape[1]),
+                             jnp.float32)
+
+        monkeypatch.setattr(ops, "cim_matmul_kernel", spy)
+        cfg = PAPER_OP_16ROWS
+        x, w = rand_codes(32, 64, 896, cfg)
+        cell = dispatch.shape_cell(32, 64, 896)
+        rule = (32, 512, 128)
+        cases = [
+            (dict(backend="pallas"), "explicit", rule),
+            (dict(backend="pallas", block=(16, 256, 64)), "explicit",
+             (16, 256, 64)),
+        ]
+        for kwargs, source, want in cases:
+            with dispatch.record_resolutions() as log:
+                dispatch.dispatch(x, w, cfg, **kwargs)
+            assert [(r.source, r.block) for r in log] == [(source, want)]
+            assert ran[-1] == want
+        cache = autotune.TuningCache(arch="test")
+        cache.put("p8t", cell, autotune.Winner("pallas", (8, 128, 128), 1.0))
+        autotune.set_active(cache)
+        with dispatch.record_resolutions() as log:
+            dispatch.dispatch(x, w, cfg)
+        assert [(r.source, r.block) for r in log] == [
+            ("tuned", (8, 128, 128))]
+        assert ran[-1] == (8, 128, 128)
+        autotune.clear_active()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with dispatch.record_resolutions() as log:
+            dispatch.dispatch(x, w, cfg)
+        assert [(r.source, r.key.backend, r.block) for r in log] == [
+            ("heuristic", "pallas", rule)]
+        assert ran[-1] == rule
+
+
 class TestAutotune:
     def fake_measure(self, order):
         def measure(cand, run):

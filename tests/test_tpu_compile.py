@@ -43,12 +43,16 @@ KERNELS = {
 }
 # qwen2-0.5b projections: prefill m=128 on gate/up (896->4864, i8
 # codes), decode m=1 on q/o (896->896) and m=8 on the packed down
-# projection (4864->896, u8 plane bytes), decode shapes at every
-# decode_blocks tiling offered for that m.
+# projection (4864->896, u8 plane bytes) at every decode_blocks tiling
+# offered for that m, and the served batch-32 decode step on gate/up
+# and down at the default block, the one dispatch runs when no block
+# is pinned (``dispatch._pallas_blocks``; prefill's is (128, 128, 128)).
 LM_CASES = {
     "prefill-m128-896x4864": (128, 896, 4864, jnp.int8, False),
     "decode-m1-896x896": (1, 896, 896, jnp.int8, True),
     "decode-m8-4864x896": (8, 4864, 896, jnp.uint8, True),
+    "decode-m32-896x4864": (32, 896, 4864, jnp.int8, False),
+    "decode-m32-4864x896": (32, 4864, 896, jnp.uint8, False),
 }
 
 
@@ -86,9 +90,10 @@ def _compile(fn, *args):
 @pytest.mark.parametrize("variant", sorted(KERNELS))
 def test_kernel_compiles_at_lm_widths(one_chip, variant, case):
     m, k, n, wdtype, decode = LM_CASES[case]
+    spec = dispatch.as_spec(PAPER_OP_16ROWS)
     blocks = (
-        autotune.decode_blocks(PAPER_OP_16ROWS.rows_active, m) if decode
-        else ((128, 128, 128),)
+        autotune.decode_blocks(spec.rows_active, m) if decode
+        else (dispatch._pallas_blocks(spec, None, m, n),)
     )
     x = jax.ShapeDtypeStruct((m, k), jnp.int32, sharding=one_chip)
     w = jax.ShapeDtypeStruct((k, n), wdtype, sharding=one_chip)
